@@ -181,6 +181,7 @@ def _time_ref_tree(ref_root: str, mode: Optional[str],
     import sys as _sys
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ref_root, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # host-only child: never contend for a chip
     payload = [[list(s) for s in SMOKE_SCENARIOS], BENCH_REPEATS]
     if mode is not None:
         payload.append(mode)
@@ -1176,6 +1177,7 @@ def _time_scale_tree(root: str, num_chips: int, n_requests: int,
     from repro.core import workloads as wl
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(root, "src")
+    env["JAX_PLATFORMS"] = "cpu"  # host-only child: never contend for a chip
     payload = {"num_chips": num_chips, "n_requests": n_requests,
                "base_chips": wl.SCALE_BASE_CHIPS, "rates": wl.SCALE_RATES,
                "aliases": wl.SCALE_ALIASES, "level": SCALE_LEVEL,

@@ -11,10 +11,12 @@ from repro.core.dispatcher import Dispatcher
 from repro.core.orchestrator import Orchestrator
 from repro.core.profiler import Profiler
 from repro.core.request import Request
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import pipeline as pl
 
 
 def main():
+    enable_compile_cache()
     # --- 1. a runnable (reduced) Stable-Diffusion-3-style pipeline ---------
     cfg = C.get_smoke("sd3")
     params = pl.init(cfg, jax.random.PRNGKey(0))

@@ -2,8 +2,9 @@
 
 ``get(arch_id)`` returns the full published config; ``get_smoke(arch_id)``
 returns a reduced same-family variant (2 layers, d_model<=512, <=4 experts)
-used by the CPU smoke tests.  The full configs are only ever exercised via
-``.lower().compile()`` dry-runs (ShapeDtypeStruct, no allocation).
+used by the CPU smoke tests.  The full configs are exercised via
+``.lower().compile()`` dry-runs (ShapeDtypeStruct, no allocation); the full
+sd3 pipeline also runs on one TPU chip in ``chip_smoke.py``.
 """
 from __future__ import annotations
 

@@ -1,8 +1,9 @@
 """Stable Diffusion 3 Medium pipeline [arXiv:2403.03206 / Table 2].
 
 Encode: T5-XXL-style bidirectional encoder (~4.8B); Diffuse: Sd3-DiT ~2B;
-Decode: AE-KL ~0.1B.  Denoising steps 20 (Table 5).  Full config is
-dry-run-only; SMOKE is the CPU-runnable reduced pipeline.
+Decode: AE-KL ~0.1B.  Denoising steps 20 (Table 5).  The full config
+serves on one TPU v5e chip at full depth (``chip_smoke.py``); SMOKE is the
+CPU-runnable reduced pipeline.
 """
 import dataclasses
 

@@ -22,6 +22,7 @@ import functools
 from typing import Dict, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.request import Request
 from repro.models import diffusion, pipeline as pipe_lib, transformer
@@ -98,7 +99,8 @@ class Profiler:
     @staticmethod
     @functools.lru_cache(maxsize=None)
     def _stage_infos_cached(cfg: pipe_lib.PipelineConfig):
-        key = jax.random.PRNGKey(0)
+        # an abstract key: planning traces shapes only, never touches a device
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32)
         enc = jax.eval_shape(lambda k: transformer.init(cfg.encoder, k), key)
         dit = jax.eval_shape(lambda k: diffusion.init(cfg.dit, k), key)
         dec = jax.eval_shape(lambda k: diffusion.init_decoder(cfg.decoder, k), key)

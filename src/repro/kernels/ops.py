@@ -2,8 +2,9 @@
 
 Every op takes ``use_kernel``: False routes to the pure-jnp oracle in
 ``ref.py`` (the CPU-correct path used by smoke tests and the serving
-examples); True routes to the Pallas TPU kernel (validated on CPU with
-``interpret=True`` in the test suite; compiled for real on TPU).
+examples); True routes to the Pallas TPU kernel, compiled for the chip.
+Interpret mode runs only when the caller passes ``interpret=True`` (the
+test suite does, on the CPU); without it a kernel call on the CPU raises.
 """
 from __future__ import annotations
 
@@ -16,8 +17,6 @@ from repro.kernels import ref
 
 Array = jax.Array
 
-_INTERPRET = jax.default_backend() == "cpu"  # interpret Pallas on CPU
-
 
 # ---------------------------------------------------------------------------
 # Flash attention
@@ -25,7 +24,7 @@ _INTERPRET = jax.default_backend() == "cpu"  # interpret Pallas on CPU
 
 def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
                     window: int = 0, softcap: float = 0.0,
-                    use_kernel: bool = False, interpret: Optional[bool] = None) -> Array:
+                    use_kernel: bool = False, interpret: bool = False) -> Array:
     """q: (B, Lq, H, D); k/v: (B, Lkv, H, D). GQA must be expanded upstream."""
     if not use_kernel:
         lq, lkv = q.shape[1], k.shape[1]
@@ -39,7 +38,7 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
         return ref.attention_ref(q, k, v, mask, softcap)
     from repro.kernels import flash_attention as fa
     return fa.flash_attention(q, k, v, causal=causal, window=window, softcap=softcap,
-                              interpret=_INTERPRET if interpret is None else interpret)
+                              interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -48,15 +47,14 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
 
 def linear_scan(q: Array, k: Array, v: Array, decay: Array, *,
                 bonus: Optional[Array] = None, initial_state: Optional[Array] = None,
-                use_kernel: bool = False, interpret: Optional[bool] = None,
+                use_kernel: bool = False, interpret: bool = False,
                 chunk: int = 32) -> Tuple[Array, Array]:
     """(B,H,L,K) inputs -> (out (B,H,L,V), final_state (B,H,K,V))."""
     if not use_kernel:
         return ref.chunked_linear_scan_ref(q, k, v, decay, bonus, initial_state, chunk)
     from repro.kernels import ssm_scan
     return ssm_scan.ssm_scan(q, k, v, decay, bonus=bonus, initial_state=initial_state,
-                             chunk=chunk,
-                             interpret=_INTERPRET if interpret is None else interpret)
+                             chunk=chunk, interpret=interpret)
 
 
 def linear_scan_decode(q: Array, k: Array, v: Array, decay: Array, state: Array,
@@ -70,9 +68,8 @@ def linear_scan_decode(q: Array, k: Array, v: Array, decay: Array, state: Array,
 # ---------------------------------------------------------------------------
 
 def adaln_rmsnorm(x: Array, scale: Array, shift: Array, *, eps: float = 1e-6,
-                  use_kernel: bool = False, interpret: Optional[bool] = None) -> Array:
+                  use_kernel: bool = False, interpret: bool = False) -> Array:
     if not use_kernel:
         return ref.adaln_rmsnorm_ref(x, scale, shift, eps)
     from repro.kernels import adaln_rmsnorm as ar
-    return ar.adaln_rmsnorm(x, scale, shift, eps=eps,
-                            interpret=_INTERPRET if interpret is None else interpret)
+    return ar.adaln_rmsnorm(x, scale, shift, eps=eps, interpret=interpret)

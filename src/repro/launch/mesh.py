@@ -10,15 +10,25 @@ before the first jax initialization.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: shardings are propagated
+    by the compiler, and plain ``jit``/``shard_map`` code needs no
+    ``jax.set_mesh`` (``make_mesh`` defaults to ``Explicit`` axes)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes(multi_pod: bool) -> Tuple[str, ...]:
@@ -29,4 +39,4 @@ def make_host_mesh(model: int = 1):
     """Degenerate mesh for CPU tests/examples (whatever devices exist)."""
     n = len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

@@ -35,6 +35,7 @@ def main():
 
     import repro.configs as C
     from repro.data import pipeline as dp
+    from repro.launch.mesh import make_mesh
     from repro.models import transformer
     from repro.sharding import partition
     from repro.training import loop
@@ -44,7 +45,7 @@ def main():
         d, m = (int(x) for x in args.mesh.split("x"))
     else:
         d, m = len(jax.devices()), 1
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = make_mesh((d, m), ("data", "model"))
     print(f"arch={cfg.name} mesh={d}x{m} devices={len(jax.devices())} "
           f"opts={sorted(opts)}")
 

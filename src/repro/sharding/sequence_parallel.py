@@ -18,19 +18,11 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.kernels import ops as kops
+from repro.kernels import ref
 
 Array = jax.Array
-
-
-def _pvary(x: Array, axes: Tuple[str, ...]) -> Array:
-    """``jax.lax.pvary`` marks a replicated value as device-varying for
-    shard_map's replication checker; on older jax (< 0.6) the primitive
-    does not exist and the check accepts the raw value."""
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axes) if fn is not None else x
 
 
 def ulysses_attention(q: Array, k: Array, v: Array, mesh: Mesh,
@@ -38,25 +30,39 @@ def ulysses_attention(q: Array, k: Array, v: Array, mesh: Mesh,
                       softcap: float = 0.0) -> Array:
     """q/k/v: (B, L, H, D) sharded on L over ``axis``; H % axis_size == 0.
 
-    Returns attention output sharded on L again.
+    L need not divide the axis size: the sequence is padded to a multiple
+    of it, padded keys are masked out, and padded queries are dropped.
+    Returns attention output (B, L, H, D) sharded on L again.
     """
     n = mesh.shape[axis]
     assert q.shape[2] % n == 0, f"heads {q.shape[2]} % {n} != 0"
+    l = q.shape[1]
+    pad = (-l) % n
+    if pad:
+        widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+        q, k, v = (jnp.pad(x, widths) for x in (q, k, v))
 
     def body(qs, ks, vs):
         # (B, L/n, H, D) -> all-to-all -> (B, L, H/n, D)
         a2a = lambda x: jax.lax.all_to_all(x, axis, split_axis=2, concat_axis=1,
                                            tiled=True)
         qh, kh, vh = a2a(qs), a2a(ks), a2a(vs)
-        out = kops.flash_attention(qh, kh, vh, causal=causal, softcap=softcap,
-                                   use_kernel=False)
+        pos = jnp.arange(l + pad)
+        mask = None
+        if pad:
+            mask = pos[None, :] < l          # no query attends to a padded key
+        if causal:
+            tri = pos[None, :] <= pos[:, None]
+            mask = tri if mask is None else mask & tri
+        out = ref.attention_ref(qh, kh, vh, mask, softcap)
         # (B, L, H/n, D) -> back to sequence sharding (B, L/n, H, D)
         return jax.lax.all_to_all(out, axis, split_axis=1, concat_axis=2,
                                   tiled=True)
 
     spec = P(None, axis, None, None)
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec)(q, k, v)
+    out = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                        out_specs=spec)(q, k, v)
+    return out[:, :l] if pad else out
 
 
 def scan_chunk_parallel(q: Array, k: Array, v: Array, decay: Array,
@@ -74,7 +80,8 @@ def scan_chunk_parallel(q: Array, k: Array, v: Array, decay: Array,
     def body(qs, ks, vs, ws):
         bb, hh, _, kk = qs.shape
         vv = vs.shape[-1]
-        zero = _pvary(jnp.zeros((bb, hh, kk, vv), jnp.float32), (axis,))
+        zero = jax.lax.pcast(jnp.zeros((bb, hh, kk, vv), jnp.float32), (axis,),
+                             to="varying")
         _, s_local = kops.linear_scan(qs, ks, vs, ws, bonus=bonus,
                                       initial_state=zero)
         # total decay of the local chunk per (B, H, K)
@@ -93,8 +100,8 @@ def scan_chunk_parallel(q: Array, k: Array, v: Array, decay: Array,
         return out, s_final[None]
 
     spec_l = P(None, None, axis, None)
-    out, s = shard_map(body, mesh=mesh,
-                       in_specs=(spec_l, spec_l, spec_l, spec_l),
-                       out_specs=(spec_l, P(axis, None, None, None, None)))(
+    out, s = jax.shard_map(body, mesh=mesh,
+                           in_specs=(spec_l, spec_l, spec_l, spec_l),
+                           out_specs=(spec_l, P(axis, None, None, None, None)))(
         q, k, v, decay)
     return out, s[-1]

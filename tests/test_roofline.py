@@ -87,8 +87,9 @@ def test_dryrun_single_combo_subprocess():
         import jax
         import repro.configs as C
         from repro.launch import specs as specs_lib, dryrun
+        from repro.launch.mesh import make_mesh
         spec = specs_lib.input_specs("internvl2-2b", "decode_32k")
-        mesh = jax.make_mesh((8, 8), ("data", "model"))
+        mesh = make_mesh((8, 8), ("data", "model"))
         cfg = C.get("internvl2-2b")
         in_sh = dryrun.shardings_for(spec, cfg, mesh, False)
         with mesh:

@@ -67,7 +67,8 @@ def test_ulysses_matches_reference_4dev():
         import jax, jax.numpy as jnp, numpy as np
         from repro.sharding import sequence_parallel as sp
         from repro.kernels import ops
-        mesh = jax.make_mesh((4,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("model",))
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
         q = jax.random.normal(ks[0], (2, 32, 8, 16))
         k = jax.random.normal(ks[1], (2, 32, 8, 16))
@@ -78,12 +79,35 @@ def test_ulysses_matches_reference_4dev():
     """)
 
 
+def test_ulysses_uneven_length_masks_padding_4dev():
+    """A length the SP degree does not divide (the DiT's 4096 + 77 joint
+    sequence is one) is padded inside and the padded keys are masked."""
+    _run_subprocess("""
+        import jax, numpy as np
+        from repro.sharding import sequence_parallel as sp
+        from repro.kernels import ops
+        from repro.launch.mesh import make_mesh
+        ks = jax.random.split(jax.random.PRNGKey(0), 3)
+        q, k, v = (jax.random.normal(kk, (1, 30, 8, 16)) for kk in ks)
+        for n in (2, 4):
+            mesh = make_mesh((n,), ("model",), devices=jax.devices()[:n])
+            for causal in (False, True):
+                out = sp.ulysses_attention(q, k, v, mesh, causal=causal)
+                ref = ops.flash_attention(q, k, v, causal=causal,
+                                          use_kernel=False)
+                assert out.shape == ref.shape
+                np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                           atol=2e-5)
+    """)
+
+
 def test_scan_chunk_parallel_matches_reference_4dev():
     _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.sharding import sequence_parallel as sp
         from repro.kernels import ref
-        mesh = jax.make_mesh((4,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("model",))
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
         q = jax.random.normal(ks[0], (2, 3, 64, 8))
         k = jax.random.normal(ks[1], (2, 3, 64, 8))
@@ -104,11 +128,12 @@ def test_sharded_train_step_runs_8dev():
         import jax, jax.numpy as jnp, numpy as np
         import repro.configs as C
         from repro.data import pipeline as dp
+        from repro.launch.mesh import make_mesh
         from repro.sharding import partition
         from repro.training import loop
         from jax.sharding import NamedSharding, PartitionSpec as P
         cfg = C.get_smoke("deepseek-moe-16b")
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         state = loop.init_state(cfg, jax.random.PRNGKey(0))
         sspec = partition.state_specs(cfg, jax.eval_shape(lambda: state))
         sspec = partition.validate_divisibility(
