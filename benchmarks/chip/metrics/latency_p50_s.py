@@ -1,0 +1,6 @@
+"""Median of due -> image on the host over every request sent, s."""
+from benchmarks.chip.metric_lib import latencies, percentile
+
+
+def read(run):
+    return percentile(latencies(run), 50)
