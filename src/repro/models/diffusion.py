@@ -4,7 +4,8 @@ Architecture follows Peebles & Xie DiT / SD3-style joint conditioning
 simplified to a single stream: latent patches and text-condition tokens are
 concatenated into one sequence; per-block modulation (shift/scale/gate x2)
 comes from the timestep + pooled-condition embedding.  Layers are
-homogeneous, executed with one ``lax.scan``.
+homogeneous, executed with one ``lax.scan``.  Each block runs under a
+named scope of ``repro.models.scopes`` (metadata only).
 
 The Diffuse stage runs ``num_steps`` denoising iterations of this network —
 the compute-dominant, SP-scalable stage the paper's dispatcher reasons
@@ -20,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
-from repro.models import common
+from repro.models import common, scopes
 from repro.models.common import Array, dense_init
 
 
@@ -98,48 +99,58 @@ def forward(cfg: DiTConfig, params: dict, latents: Array, t: Array,
     h = cfg.num_heads
     dh = cfg.d_model // h
 
-    x = jnp.einsum("blc,cd->bld", latents.astype(cfg.dtype), params["x_in"])
-    c = jnp.einsum("blc,cd->bld", cond.astype(cfg.dtype), params["cond_in"])
-    x = jnp.concatenate([c, x], axis=1)                       # joint stream
-    l = lx + lc
+    with jax.named_scope(scopes.DIT_EMBED):
+        x = jnp.einsum("blc,cd->bld", latents.astype(cfg.dtype), params["x_in"])
+        c = jnp.einsum("blc,cd->bld", cond.astype(cfg.dtype), params["cond_in"])
+        x = jnp.concatenate([c, x], axis=1)                   # joint stream
+        l = lx + lc
 
-    # absolute 2-channel sin/cos positions (latent grid is 1D-flattened here)
-    pos = jnp.arange(l, dtype=jnp.float32)
-    pf = params["pos_freq"].astype(jnp.float32)
-    pe = jnp.concatenate([jnp.sin(pos[:, None] * pf[0][None]),
-                          jnp.cos(pos[:, None] * pf[1][None])], axis=-1)
-    x = x + pe[None].astype(cfg.dtype)
+        # absolute 2-channel sin/cos positions (latent grid is 1D-flattened here)
+        pos = jnp.arange(l, dtype=jnp.float32)
+        pf = params["pos_freq"].astype(jnp.float32)
+        pe = jnp.concatenate([jnp.sin(pos[:, None] * pf[0][None]),
+                              jnp.cos(pos[:, None] * pf[1][None])], axis=-1)
+        x = x + pe[None].astype(cfg.dtype)
 
-    temb = timestep_embedding(t, cfg.time_embed_dim)
-    tc = jnp.einsum("be,ed->bd", temb.astype(cfg.dtype), params["t_mlp1"])
-    if cond_pooled is not None:
-        tc = tc + cond_pooled.astype(cfg.dtype)
-    tc = jnp.einsum("bd,de->be", jax.nn.silu(tc.astype(jnp.float32)).astype(cfg.dtype),
-                    params["t_mlp2"])
+        temb = timestep_embedding(t, cfg.time_embed_dim)
+        tc = jnp.einsum("be,ed->bd", temb.astype(cfg.dtype), params["t_mlp1"])
+        if cond_pooled is not None:
+            tc = tc + cond_pooled.astype(cfg.dtype)
+        tc = jnp.einsum("bd,de->be",
+                        jax.nn.silu(tc.astype(jnp.float32)).astype(cfg.dtype),
+                        params["t_mlp2"])
 
     def block(x, p):
-        mod = jnp.einsum("bd,de->be", tc, p["mod"]).reshape(b, 6, cfg.d_model)
-        s1, sh1, g1, s2, sh2, g2 = [mod[:, i] for i in range(6)]
-        hn = _modulated_norm(cfg, x, s1, sh1)
-        q = jnp.einsum("bld,de->ble", hn, p["wq"]).reshape(b, l, h, dh)
-        k = jnp.einsum("bld,de->ble", hn, p["wk"]).reshape(b, l, h, dh)
-        v = jnp.einsum("bld,de->ble", hn, p["wv"]).reshape(b, l, h, dh)
-        if cfg.use_flash:
-            a = kops.flash_attention(q, k, v, causal=False, use_kernel=True)
-        else:
-            a = common.attention(q, k, v, None)
-        a = jnp.einsum("ble,ed->bld", a.reshape(b, l, cfg.d_model), p["wo"])
-        x = x + g1[:, None, :] * a
-        hn = _modulated_norm(cfg, x, s2, sh2)
-        f = common.gelu_mlp(hn, p["w_up"], p["w_down"])
-        x = x + g2[:, None, :] * f
+        with jax.named_scope(scopes.DIT_ADALN):
+            mod = jnp.einsum("bd,de->be", tc, p["mod"]).reshape(b, 6, cfg.d_model)
+            s1, sh1, g1, s2, sh2, g2 = [mod[:, i] for i in range(6)]
+            hn = _modulated_norm(cfg, x, s1, sh1)
+        with jax.named_scope(scopes.DIT_QKV):
+            q = jnp.einsum("bld,de->ble", hn, p["wq"]).reshape(b, l, h, dh)
+            k = jnp.einsum("bld,de->ble", hn, p["wk"]).reshape(b, l, h, dh)
+            v = jnp.einsum("bld,de->ble", hn, p["wv"]).reshape(b, l, h, dh)
+        with jax.named_scope(scopes.DIT_ATTENTION):
+            if cfg.use_flash:
+                a = kops.flash_attention(q, k, v, causal=False, use_kernel=True)
+            else:
+                a = common.attention(q, k, v, None)
+        with jax.named_scope(scopes.DIT_ATTN_OUT):
+            a = jnp.einsum("ble,ed->bld", a.reshape(b, l, cfg.d_model), p["wo"])
+            x = x + g1[:, None, :] * a
+        with jax.named_scope(scopes.DIT_ADALN):
+            hn = _modulated_norm(cfg, x, s2, sh2)
+        with jax.named_scope(scopes.DIT_MLP):
+            f = common.gelu_mlp(hn, p["w_up"], p["w_down"])
+            x = x + g2[:, None, :] * f
         return x, 0
 
-    x, _ = jax.lax.scan(block, x, params["layers"])
-    fmod = jnp.einsum("bd,de->be", tc, params["final_mod"]).reshape(b, 2, cfg.d_model)
-    x = _modulated_norm(cfg, x, fmod[:, 0], fmod[:, 1])
-    eps = jnp.einsum("bld,dc->blc", x[:, lc:, :], params["x_out"])
-    return eps.astype(jnp.float32)
+    with jax.named_scope(scopes.DIT_LAYERS):
+        x, _ = jax.lax.scan(block, x, params["layers"])
+    with jax.named_scope(scopes.DIT_FINAL):
+        fmod = jnp.einsum("bd,de->be", tc, params["final_mod"]).reshape(b, 2, cfg.d_model)
+        x = _modulated_norm(cfg, x, fmod[:, 0], fmod[:, 1])
+        eps = jnp.einsum("bld,dc->blc", x[:, lc:, :], params["x_out"])
+        return eps.astype(jnp.float32)
 
 
 def ddim_denoise(cfg: DiTConfig, params: dict, noise: Array, cond: Array,
@@ -148,19 +159,23 @@ def ddim_denoise(cfg: DiTConfig, params: dict, noise: Array, cond: Array,
 
     DDIM with a linear alpha-bar schedule; deterministic (eta=0).
     """
-    betas = jnp.linspace(1e-4, 0.02, 1000, dtype=jnp.float32)
-    alpha_bar = jnp.cumprod(1.0 - betas)
-    ts = jnp.linspace(999, 0, num_steps).astype(jnp.int32)
+    with jax.named_scope(scopes.DDIM):
+        betas = jnp.linspace(1e-4, 0.02, 1000, dtype=jnp.float32)
+        alpha_bar = jnp.cumprod(1.0 - betas)
+        ts = jnp.linspace(999, 0, num_steps).astype(jnp.int32)
 
     def step(i, x):
-        t = ts[i]
-        t_next = jnp.where(i + 1 < num_steps, ts[jnp.minimum(i + 1, num_steps - 1)], -1)
-        ab_t = alpha_bar[t]
-        ab_n = jnp.where(t_next >= 0, alpha_bar[jnp.maximum(t_next, 0)], 1.0)
-        tb = jnp.full((x.shape[0],), t, jnp.float32)
+        with jax.named_scope(scopes.DDIM):
+            t = ts[i]
+            t_next = jnp.where(i + 1 < num_steps,
+                               ts[jnp.minimum(i + 1, num_steps - 1)], -1)
+            ab_t = alpha_bar[t]
+            ab_n = jnp.where(t_next >= 0, alpha_bar[jnp.maximum(t_next, 0)], 1.0)
+            tb = jnp.full((x.shape[0],), t, jnp.float32)
         eps = forward(cfg, params, x, tb, cond)
-        x0 = (x - jnp.sqrt(1 - ab_t) * eps) / jnp.sqrt(ab_t)
-        return jnp.sqrt(ab_n) * x0 + jnp.sqrt(1 - ab_n) * eps
+        with jax.named_scope(scopes.DDIM):
+            x0 = (x - jnp.sqrt(1 - ab_t) * eps) / jnp.sqrt(ab_t)
+            return jnp.sqrt(ab_n) * x0 + jnp.sqrt(1 - ab_n) * eps
 
     return jax.lax.fori_loop(0, num_steps, step, noise)
 
@@ -211,14 +226,17 @@ def decode_latent(cfg: DecoderConfig, params: dict, z: Array) -> Array:
     model accounts for the heavier 3D-conv + temporal-upsample cost of the
     real AE; see DESIGN.md §assumptions).
     """
-    x = _conv(z.astype(cfg.dtype), params["conv_in"])
+    with jax.named_scope(scopes.DECODER_CONV_IN):
+        x = _conv(z.astype(cfg.dtype), params["conv_in"])
     for i in range(cfg.num_upsamples):
-        b, hh, ww, c = x.shape
+        with jax.named_scope(scopes.decoder_up(i)):
+            b, hh, ww, c = x.shape
+            x = jax.nn.silu(x.astype(jnp.float32)).astype(cfg.dtype)
+            x = jax.image.resize(x, (b, hh * 2, ww * 2, c), "nearest")
+            x = _conv(x, params[f"up{i}_in"])
+            for r in range(cfg.res_blocks):
+                h = jax.nn.silu(x.astype(jnp.float32)).astype(cfg.dtype)
+                x = x + _conv(h, params[f"up{i}_res{r}"])
+    with jax.named_scope(scopes.DECODER_CONV_OUT):
         x = jax.nn.silu(x.astype(jnp.float32)).astype(cfg.dtype)
-        x = jax.image.resize(x, (b, hh * 2, ww * 2, c), "nearest")
-        x = _conv(x, params[f"up{i}_in"])
-        for r in range(cfg.res_blocks):
-            h = jax.nn.silu(x.astype(jnp.float32)).astype(cfg.dtype)
-            x = x + _conv(h, params[f"up{i}_res{r}"])
-    x = jax.nn.silu(x.astype(jnp.float32)).astype(cfg.dtype)
-    return jnp.tanh(_conv(x, params["conv_out"]).astype(jnp.float32))
+        return jnp.tanh(_conv(x, params["conv_out"]).astype(jnp.float32))

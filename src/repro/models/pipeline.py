@@ -17,7 +17,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models import common, diffusion, transformer
+from repro.models import common, diffusion, scopes, transformer
 from repro.models.common import Array, ModelConfig
 
 
@@ -70,7 +70,8 @@ def diffuse(cfg: PipelineConfig, params: Dict, cond: Array, latent_shape,
             key: Array, num_steps: Optional[int] = None) -> Array:
     """Stage D: T-step denoising from Gaussian noise in latent space."""
     steps = num_steps or cfg.num_steps
-    noise = jax.random.normal(key, latent_shape, jnp.float32)
+    with jax.named_scope(scopes.DDIM):
+        noise = jax.random.normal(key, latent_shape, jnp.float32)
     return diffusion.ddim_denoise(cfg.dit, params["diffuse"], noise, cond, steps)
 
 

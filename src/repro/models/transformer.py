@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
-from repro.models import common, moe as moe_lib, ssm as ssm_lib
+from repro.models import common, moe as moe_lib, scopes, ssm as ssm_lib
 from repro.models.common import (ATTN, ATTN_BIDIR, ATTN_CHUNKED, ATTN_KINDS,
                                  ATTN_LOCAL, FFN_MOE, MAMBA2, RWKV6, Array,
                                  ModelConfig, dense_init, embed_init)
@@ -312,17 +312,20 @@ def _layer_fwd(cfg, kind, p, x, positions, cache, mode, offset):
     mixer, ffn_kind = kind
     aux = 0.0
     if mixer in ATTN_KINDS:
-        h = common.rms_norm(x, p["ln1"], cfg.norm_eps)
-        if mode == "decode":
-            out, new_cache = _attn_decode(cfg, p, h, mixer, offset, cache)
-        else:
-            out, (k, v) = _attn_nocache(cfg, p, h, mixer, positions)
-            new_cache = None
-            if mode == "prefill":
-                cap = cache["k"].shape[1]
-                new_cache = _fill_cache_from_prefill(cfg, mixer, k, v, positions, cap)
-        x = x + out
-        x, aux = _ffn_apply(cfg, ffn_kind, p, x)
+        with jax.named_scope(scopes.ENCODER_ATTENTION):
+            h = common.rms_norm(x, p["ln1"], cfg.norm_eps)
+            if mode == "decode":
+                out, new_cache = _attn_decode(cfg, p, h, mixer, offset, cache)
+            else:
+                out, (k, v) = _attn_nocache(cfg, p, h, mixer, positions)
+                new_cache = None
+                if mode == "prefill":
+                    cap = cache["k"].shape[1]
+                    new_cache = _fill_cache_from_prefill(cfg, mixer, k, v,
+                                                         positions, cap)
+            x = x + out
+        with jax.named_scope(scopes.ENCODER_MLP):
+            x, aux = _ffn_apply(cfg, ffn_kind, p, x)
     elif mixer == MAMBA2:
         h = common.rms_norm(x, p["ln1"], cfg.norm_eps)
         if mode == "decode":
@@ -383,7 +386,8 @@ def _run_segments(cfg, params, x, positions, caches, mode, offset):
         if mode == "train" and cfg.remat:
             body = jax.checkpoint(body)  # recompute in bwd; no stacked stash
         xs = (p_blk, c_blk) if c_blk is not None else p_blk
-        (x, total_aux), ys = jax.lax.scan(body, (x, total_aux), xs)
+        with jax.named_scope(scopes.ENCODER_LAYERS):
+            (x, total_aux), ys = jax.lax.scan(body, (x, total_aux), xs)
         new_caches.append(ys if c_blk is not None else None)
     return x, new_caches, total_aux
 

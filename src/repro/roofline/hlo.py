@@ -13,6 +13,11 @@ multipliers through while-body/fusion/call edges, and accumulate:
   HBM — this matches the XLA execution model);
 * **collectives** — wire bytes per op kind, ring-scaled, x loop multiplier.
 
+``op_scopes`` reads the program's named scopes back from the same text
+(each instruction's ``metadata={op_name=".../<scope>/<op>"}``),
+``fused_work_scopes`` the scopes whose work each fusion computes, and
+``scope_flops`` counts the flops of each scope.
+
 Validated against an unrolled single-device lowering in
 tests/test_roofline.py (scan vs unroll agree).
 """
@@ -30,9 +35,11 @@ _DTYPE_BYTES = {
 
 _COMP_HDR = re.compile(
     r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\((?:[^()]|\([^()]*\))*\)\s*->")
+# a tuple's element layouts may hold one level of parentheses (TPU tiling:
+# ``{1,0:T(8,128)(2,1)}``)
 _OP_LINE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\([^)]*\)|[\w]+\[[\d,]*\](?:\{[^}]*\})?)\s*"
-    r"([\w\-]+)\(")
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\((?:[^()]|\([^()]*\))*\)|[\w]+\[[\d,]*\]"
+    r"(?:\{[^}]*\})?)\s*([\w\-]+)\(")
 _SHAPE = re.compile(r"(\w+)\[([\d,]*)\]")
 _TRIP = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
 _BODY = re.compile(r"body=%?([\w.\-]+)")
@@ -42,6 +49,7 @@ _COND = re.compile(r"condition=%?([\w.\-]+)")
 _LHS_C = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
 _RG = re.compile(r"replica_groups=\{\{([^}]*)\}")
 _RG2 = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
+_OP_NAME = re.compile(r'metadata=\{[^}]*\bop_name="([^"]*)"')
 
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
@@ -172,11 +180,11 @@ def _symbol_table(comps: Dict[str, List[Op]]) -> Dict[str, List[Tuple[str, List[
 
 
 def _operands(line: str) -> List[str]:
-    m = re.search(r"\(([^()]*(?:\([^()]*\)[^()]*)*)\)", line.split("=", 1)[1])
+    """Names of the operands: what follows the opcode, up to its ``)``."""
+    m = _OP_LINE.match(line)
     if not m:
         return []
-    names = re.findall(r"%([\w.\-]+)", m.group(1))
-    return names
+    return re.findall(r"%([\w.\-]+)", line[m.end():].split(")", 1)[0])
 
 
 def _dot_flops(op: Op, table) -> float:
@@ -375,3 +383,119 @@ def module_costs(text: str, num_devices: int) -> ModuleCosts:
                        collective_counts=coll_counts,
                        loop_multipliers={k: v for k, v in mult.items()
                                          if v > 1.0})
+
+
+def _scope_of(op_name: str, names) -> Optional[str]:
+    """The innermost of ``names`` whose ``/``-separated parts appear in
+    ``op_name`` as consecutive parts."""
+    parts = op_name.split("/")
+    best, best_at = None, -1
+    for name in names:
+        want = name.split("/")
+        n = len(want)
+        for i in range(len(parts) - n, -1, -1):
+            if parts[i:i + n] == want:
+                if i > best_at:
+                    best, best_at = name, i
+                break
+    return best
+
+
+def op_scopes(text: str, names) -> Dict[str, str]:
+    """{instruction name: scope} for every instruction of the module whose
+    metadata names one of ``names`` (``jax.named_scope`` names such as
+    ``dit/attention``).  A fusion whose own metadata names none takes the
+    scope most of its fused computation's instructions carry.  An
+    instruction with no metadata at all (one a compiler pass made, such as
+    a dot with its batch dimensions moved) takes the scope most of its
+    users carry, else most of its operands."""
+    comps = parse_computations(text)
+    out: Dict[str, str] = {}
+    bare: List[Op] = []
+    for ops in comps.values():
+        for op in ops:
+            m = _OP_NAME.search(op.line)
+            if m is None:
+                bare.append(op)
+                continue
+            scope = _scope_of(m.group(1), names)
+            if scope is not None:
+                out[op.name] = scope
+    for ops in comps.values():
+        for op in ops:
+            if op.opcode == "fusion" and op.name not in out:
+                cm = _CALLS.search(op.line)
+                inner = comps.get(cm.group(1), []) if cm else []
+                _vote(out, op.name, [i.name for i in inner])
+    users: Dict[str, List[str]] = {}
+    for ops in comps.values():
+        for op in ops:
+            for o in _operands(op.line):
+                users.setdefault(o, []).append(op.name)
+    for op in bare:
+        if op.name not in out and not _vote(out, op.name, users.get(op.name, [])):
+            _vote(out, op.name, _operands(op.line))
+    return out
+
+
+def _vote(scopes: Dict[str, str], name: str, others: List[str]) -> bool:
+    """Give ``name`` the scope most of ``others`` carry, if any carries one."""
+    votes: Dict[str, int] = {}
+    for o in others:
+        if o in scopes:
+            votes[scopes[o]] = votes.get(scopes[o], 0) + 1
+    if votes:
+        scopes[name] = max(sorted(votes), key=votes.get)
+    return bool(votes)
+
+
+def fused_work_scopes(text: str, names) -> Dict[str, frozenset]:
+    """{fusion name: the scopes of the dots and convolutions it computes}
+    for each fusion that computes any, nested fusions included.  A fusion
+    whose work spans several scopes is one the compiler made across scope
+    boundaries (a projection fused into the attention's product)."""
+    comps = parse_computations(text)
+    where = op_scopes(text, names)
+    memo: Dict[str, frozenset] = {}
+
+    def work(cname: str) -> frozenset:
+        if cname not in memo:
+            acc = set()
+            for op in comps.get(cname, []):
+                if op.opcode in ("dot", "convolution") and op.name in where:
+                    acc.add(where[op.name])
+                cm = _CALLS.search(op.line)
+                if cm:
+                    acc |= work(cm.group(1))
+            memo[cname] = frozenset(acc)
+        return memo[cname]
+
+    out: Dict[str, frozenset] = {}
+    for ops in comps.values():
+        for op in ops:
+            cm = _CALLS.search(op.line) if op.opcode == "fusion" else None
+            if cm and work(cm.group(1)):
+                out[op.name] = work(cm.group(1))
+    return out
+
+
+def scope_flops(text: str, names) -> Dict[Optional[str], float]:
+    """FLOPs of every ``dot`` and ``convolution`` per scope (``None`` for
+    those outside every scope), each times its loop multiplier, as
+    ``module_costs`` counts them."""
+    comps = parse_computations(text)
+    mult = loop_multipliers(text, comps)
+    table = _symbol_table(comps)
+    scopes = op_scopes(text, names)
+    out: Dict[Optional[str], float] = {}
+    for cname, ops in comps.items():
+        for op in ops:
+            if op.opcode == "dot":
+                f = _dot_flops(op, table)
+            elif op.opcode == "convolution":
+                f = _conv_flops(op, table)
+            else:
+                continue
+            key = scopes.get(op.name)
+            out[key] = out.get(key, 0.0) + mult.get(cname, 1.0) * f
+    return out
