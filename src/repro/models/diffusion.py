@@ -14,6 +14,7 @@ about.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional
 
@@ -23,6 +24,12 @@ import jax.numpy as jnp
 from repro.kernels import ops as kops
 from repro.models import common, scopes
 from repro.models.common import Array, dense_init
+
+
+# joint length from which the flash kernel beats XLA's attention on a TPU
+# v5e: XLA is faster at 141 and 333, the kernel at 1101 and 4173 (PERF.md,
+# benchmarks/attention_bench.py)
+FLASH_MIN_LEN = 1024
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,7 +44,6 @@ class DiTConfig:
     time_embed_dim: int = 256
     dtype: Any = jnp.bfloat16
     norm_eps: float = 1e-6
-    use_flash: bool = False
     use_fused_adaln: bool = False  # route modulated norms through the Pallas kernel
     source: str = ""
 
@@ -87,6 +93,22 @@ def _modulated_norm(cfg: DiTConfig, x, scale, shift):
     return kops.adaln_rmsnorm(x, scale, shift, eps=cfg.norm_eps, use_kernel=False)
 
 
+def joint_attention(q: Array, k: Array, v: Array) -> Array:
+    """Bidirectional attention over the joint sequence, (B, L, H, Dh).
+
+    On a TPU, from ``FLASH_MIN_LEN`` positions on, the Pallas flash kernel,
+    which never writes the (H, L, L) scores to HBM; below it XLA's
+    materialised scores, which the compiler fuses with the projections.
+    Every other platform lowers ``common.attention`` alone.
+    """
+    xla = functools.partial(common.attention, mask=None)
+    if q.shape[1] < FLASH_MIN_LEN:
+        return xla(q, k, v)
+    return jax.lax.platform_dependent(
+        q, k, v, default=xla,
+        tpu=functools.partial(kops.flash_attention, causal=False, use_kernel=True))
+
+
 def forward(cfg: DiTConfig, params: dict, latents: Array, t: Array,
             cond: Array, cond_pooled: Optional[Array] = None) -> Array:
     """One denoising network evaluation.
@@ -130,10 +152,7 @@ def forward(cfg: DiTConfig, params: dict, latents: Array, t: Array,
             k = jnp.einsum("bld,de->ble", hn, p["wk"]).reshape(b, l, h, dh)
             v = jnp.einsum("bld,de->ble", hn, p["wv"]).reshape(b, l, h, dh)
         with jax.named_scope(scopes.DIT_ATTENTION):
-            if cfg.use_flash:
-                a = kops.flash_attention(q, k, v, causal=False, use_kernel=True)
-            else:
-                a = common.attention(q, k, v, None)
+            a = joint_attention(q, k, v)
         with jax.named_scope(scopes.DIT_ATTN_OUT):
             a = jnp.einsum("ble,ed->bld", a.reshape(b, l, cfg.d_model), p["wo"])
             x = x + g1[:, None, :] * a

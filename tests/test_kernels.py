@@ -8,6 +8,7 @@ from repro.kernels import adaln_rmsnorm as ar
 from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 from repro.kernels import ssm_scan
+from repro.models import common
 
 
 @pytest.mark.parametrize("b,lq,lkv,h,d", [
@@ -46,6 +47,48 @@ def test_flash_attention_variants(window, softcap, causal):
                                softcap=softcap, use_kernel=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=3e-5, rtol=3e-5)
+
+
+def _qkv(key, shape, dtype=jnp.bfloat16):
+    return [jax.random.normal(k, shape, dtype) for k in jax.random.split(key, 3)]
+
+
+def _rel_err(got, want):
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+@pytest.mark.parametrize("l,h,d", [
+    (141, 2, 64), (333, 2, 64), (1024, 1, 64), (1101, 2, 64), (2117, 1, 128),
+])
+def test_flash_attention_joint_sequence(l, h, d):
+    """The DiT's non-causal joint sequence in bf16, at its default tiles:
+    lengths that need padding to 128 (all but 1024) and a tail chunk."""
+    q, k, v = _qkv(jax.random.PRNGKey(l), (1, l, h, d))
+    out = fa.flash_attention(q, k, v, causal=False, interpret=True)
+    want = common.attention(q, k, v, None)
+    assert out.shape == want.shape and out.dtype == want.dtype
+    assert _rel_err(out, want) < 1e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2, rtol=2e-2)
+
+
+def test_flash_attention_masks_padded_keys():
+    """Whatever the padded key and value rows hold, the output is the same."""
+    l, lp, d = 333, 384, 64
+    q, k, v = _qkv(jax.random.PRNGKey(7), (2, l, d))
+    pad = lambda x, fill: jnp.concatenate(
+        [x, jnp.full((2, lp - l, d), fill, x.dtype)], axis=1)
+    run = lambda fill: fa._flash_padded(
+        pad(q, 0), pad(k, fill), pad(v, fill), kv_len=l, causal=False, window=0,
+        softcap=0.0, block_q=lp, block_k=128, block_kv=lp, q_offset=0,
+        interpret=True)[:, :l]
+    clean = run(0.0)
+    np.testing.assert_array_equal(np.asarray(run(1e30), np.float32),
+                                  np.asarray(clean, np.float32))
+    heads_minor = lambda x: x.swapaxes(0, 1)[None]           # (1, L, H, D)
+    want = common.attention(*map(heads_minor, (q, k, v)), None)[0].swapaxes(0, 1)
+    assert _rel_err(clean, want) < 1e-2
 
 
 @pytest.mark.parametrize("b,h,l,dk,dv,bonus", [

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import pytest
 
 import repro.configs as C
+from repro.models import diffusion
 from repro.models import pipeline as pl
 from repro.models import scopes
 from repro.roofline import hlo
@@ -68,6 +69,24 @@ def test_scopes_change_only_metadata(sd3_texts, monkeypatch):
     for stage in "EDC":
         assert "dit/" not in bare[stage] and "decoder/" not in bare[stage]
         assert _strip(bare[stage]) == _strip(texts[stage]), stage
+
+
+def test_cpu_diffuse_stage_keeps_xla_attention_at_kernel_lengths(monkeypatch):
+    """Past the flash kernel's joint length the D stage lowered for the CPU
+    holds no kernel and is the program XLA's attention alone gives."""
+    cfg = C.get_smoke("sd3")
+    res = 512
+    assert cfg.latent_tokens(res) >= diffusion.FLASH_MIN_LEN
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    params = jax.eval_shape(lambda k: pl.init(cfg, k), key)
+    cond = jax.ShapeDtypeStruct((1, 8, cfg.dit.cond_dim), jnp.bfloat16)
+    lat = (1, cfg.latent_tokens(res), cfg.dit.latent_dim)
+    compile_d = lambda: jax.jit(lambda p, c, k: pl.diffuse(cfg, p, c, lat, k)).lower(
+        params, cond, key).compile().as_text()
+    text = compile_d()
+    assert "custom-call" not in text
+    monkeypatch.setattr(diffusion, "FLASH_MIN_LEN", 10 ** 9)
+    assert _strip(compile_d()) == _strip(text)
 
 
 @pytest.mark.parametrize("cell", ["sd3.preview", "flux.surge"])

@@ -6,6 +6,7 @@ topology is described inside a module fixture (never at import), so every
 xdist worker collects the same tests and only the worker given this file
 loads the TPU library.
 """
+import dataclasses
 import functools
 
 import jax
@@ -15,8 +16,9 @@ from jax.sharding import SingleDeviceSharding
 
 import repro.configs as C
 from repro.kernels import ops
-from repro.models import diffusion
+from repro.models import diffusion, scopes
 from repro.models import pipeline as pl
+from repro.roofline import hlo
 
 V5E_HBM_BYTES = 16 * 2 ** 30
 
@@ -65,19 +67,51 @@ def test_adaln_rmsnorm_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in fn.lower(x, mod, mod).compile().as_text()
 
 
-def test_sd3_diffuse_stage_fits_v5e(one_chip):
-    """The sd3 D stage, 20 steps at 512 px, fits one chip's HBM."""
-    cfg = C.get("sd3")
+def _diffuse_compiled(sharding, arch: str, res: int, layers=None):
+    """The D stage of ``arch`` at ``res`` px (``layers`` DiT layers if
+    given), compiled for the described chip."""
+    cfg = C.get(arch)
+    if layers is not None:
+        cfg = dataclasses.replace(cfg, dit=dataclasses.replace(cfg.dit, num_layers=layers))
     shapes = jax.eval_shape(functools.partial(diffusion.init, cfg.dit),
                             jax.ShapeDtypeStruct((2,), jnp.uint32))
     params = {"diffuse": jax.tree.map(
-        lambda s: _sds(s.shape, s.dtype, one_chip), shapes)}
-    cond = _sds((1, 77, cfg.dit.cond_dim), jnp.bfloat16, one_chip)
-    key = _sds((2,), jnp.uint32, one_chip)
-    latent_shape = (1, cfg.latent_tokens(512), cfg.dit.latent_dim)
+        lambda s: _sds(s.shape, s.dtype, sharding), shapes)}
+    cond = _sds((1, 77, cfg.dit.cond_dim), jnp.bfloat16, sharding)
+    key = _sds((2,), jnp.uint32, sharding)
+    latent_shape = (1, cfg.latent_tokens(res), cfg.dit.latent_dim)
     fn = jax.jit(functools.partial(pl.diffuse, cfg), static_argnums=(2,))
-    mem = fn.lower(params, cond, latent_shape, key).compile().memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert mem.argument_size_in_bytes > 2 ** 30   # the 24 x 1536 DiT weights
-    assert total < V5E_HBM_BYTES, total
+    return fn.lower(params, cond, latent_shape, key).compile()
+
+
+def _hbm_bytes(compiled):
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def test_sd3_diffuse_stage_fits_v5e(one_chip):
+    """The sd3 D stage, 20 steps at 512 px, fits one chip's HBM."""
+    compiled = _diffuse_compiled(one_chip, "sd3", 512)
+    assert compiled.memory_analysis().argument_size_in_bytes > 2 ** 30  # 24 x 1536 DiT
+    assert _hbm_bytes(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("arch,res,layers", [("sd3", 512, None), ("flux", 1024, 6)])
+def test_long_diffuse_stage_runs_flash_kernel_on_v5e(one_chip, arch, res, layers):
+    """From the kernel's joint length on, D's attention is the Pallas call,
+    named by the ``dit/attention`` scope, and the stage fits one chip."""
+    compiled = _diffuse_compiled(one_chip, arch, res, layers)
+    text = compiled.as_text()
+    calls = [op.name for ops in hlo.parse_computations(text).values() for op in ops
+             if "tpu_custom_call" in op.line]
+    assert calls
+    where = hlo.op_scopes(text, scopes.DIFFUSE)
+    assert {where.get(c) for c in calls} == {scopes.DIT_ATTENTION}
+    assert _hbm_bytes(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("res", [128, 256])
+def test_short_diffuse_stage_keeps_xla_attention_on_v5e(one_chip, res):
+    """Below the kernel's joint length the sd3 D stage holds no kernel."""
+    assert "tpu_custom_call" not in _diffuse_compiled(one_chip, "sd3", res).as_text()
