@@ -1,7 +1,14 @@
-"""CogVideoX1.5-5B pipeline [arXiv:2408.06072 / Table 2].
+"""The simulator's stand-in for CogVideoX [arXiv:2408.06072 / Table 2].
 
-Encode: T5 (~0.35B per Table 2); Diffuse: Cog-DiT ~4.2B; Decode:
-AE-KL-Cog ~0.45B.  Video latents (4x temporal compression).  Steps 6.
+Sizes for planning and simulation only: a 12-layer T5-small-width encoder
+(d 1024), 25 single-stream DiT layers at d 3072 (48 heads), an AE-KL
+decoder with 4 residual blocks per level, 6 steps, square latents whose
+frame count comes from seconds at 16 fps.  The fleet, simulator and
+profiler tests are pinned to these sizes.  The chip benchmark serves
+CogVideoX-5B at its published widths instead
+(``benchmarks/chip/configs/cogvideox.json``: the T5-XXL encoder, the
+cogvideox block of ``repro.models.diffusion`` at 48 heads of 64 with
+guidance, 49 frames of 720 x 480).
 """
 import dataclasses
 

@@ -137,15 +137,18 @@ class Profiler:
     # -- FLOPs / bytes per stage ----------------------------------------------
 
     def stage_flops(self, req: Request, stage: str) -> float:
+        """With guidance, E encodes the empty prompt too and D runs each
+        step on a batch of two."""
+        rows = self.cfg.dit.cond_batch
         if stage == "E":
             i = self.info["E"]
             l = req.cond_len
-            return 2.0 * i.params * l + 4.0 * i.num_layers * l * l * i.d_model
+            return rows * (2.0 * i.params * l + 4.0 * i.num_layers * l * l * i.d_model)
         if stage == "D":
             i = self.info["D"]
             l = self.latent_tokens(req) + req.cond_len
             per_step = 2.0 * i.params * l + 4.0 * i.num_layers * l * l * i.d_model
-            return per_step * self.cfg.num_steps
+            return rows * per_step * self.cfg.num_steps
         flops, _, _ = self._decoder_cost(req)
         return flops
 
@@ -159,12 +162,11 @@ class Profiler:
         """
         dec = self.cfg.decoder
         f_lat, h, w = self.cfg.latent_grid(req.resolution, req.seconds)
-        side = 2 * h                       # after un-patchify
         kernel = 18 if self.cfg.is_video else 9  # video AEs use factorized 2+1D convs
         convs = 1 + 2 * dec.res_blocks     # per level (res blocks = 2 convs)
         flops = act = 0.0
         for lvl in range(dec.num_upsamples + 1):
-            spatial = (side * (2 ** lvl)) ** 2
+            spatial = 4 * h * w * 4 ** lvl    # after un-patchify: 2h x 2w
             frames = (f_lat * (2 ** min(lvl, 2))) if self.cfg.is_video else 1
             cc = max(dec.base_channels // (2 ** lvl), 128)
             flops += spatial * frames * cc * cc * kernel * 2 * convs
@@ -172,14 +174,25 @@ class Profiler:
         return flops, act, self.info["C"].bytes + act * 2
 
     def stage_act_bytes(self, req: Request, stage: str) -> float:
-        """Peak activation bytes at degree 1 (shards ~1/k with SP)."""
+        """Peak activation bytes at degree 1 (shards ~1/k with SP); the
+        guided batch holds two rows."""
+        rows = self.cfg.dit.cond_batch
         if stage == "E":
-            return req.cond_len * self.info["E"].d_model * 2 * 12
+            return rows * req.cond_len * self.info["E"].d_model * 2 * 12
         if stage == "D":
             l = self.latent_tokens(req) + req.cond_len
-            return l * self.info["D"].d_model * 2 * 24
+            return rows * l * self.info["D"].d_model * 2 * 24
         _, act, _ = self._decoder_cost(req)
         return act
+
+    def d_live_bytes(self, req: Request) -> float:
+        """Bytes D holds live at its peak: one layer's forward pass keeps
+        the stream, its normed copy, q, k, v, the attention's output and
+        the MLP's hidden of 4d, ten (l, d) bf16 tensors per row (the 480-px
+        cogvideox D program compiled for a v5e holds 2.06 GiB, 9.9 per row
+        of its guided batch)."""
+        l = self.latent_tokens(req) + req.cond_len
+        return self.cfg.dit.cond_batch * l * self.info["D"].d_model * 2 * 10
 
     def stage_hbm_bytes(self, req: Request, stage: str) -> float:
         """Total HBM traffic (params re-read per step + activations)."""
@@ -318,6 +331,8 @@ class Profiler:
         k_chips = k_units * self.k_min
 
         def act(s):
+            if s == "D":
+                return self.d_live_bytes(req) / k_chips
             a = self.stage_act_bytes(req, s) / k_chips
             return min(a, 4 * 2 ** 30) if s == "C" else a
 
@@ -341,7 +356,7 @@ class Profiler:
     def comm_bytes(self, req: Request, edge: str) -> float:
         """Q_ED / Q_DC tensor volumes (bf16)."""
         if edge == "ED":
-            return req.cond_len * self.info["E"].d_model * 2.0
+            return self.cfg.dit.cond_batch * req.cond_len * self.info["E"].d_model * 2.0
         if edge == "DC":
             return self.latent_tokens(req) * self.cfg.dit.latent_dim * 2.0
         raise KeyError(edge)

@@ -179,9 +179,11 @@ def _flash_padded(qt: Array, kt: Array, vt: Array, *, kv_len: int, causal: bool,
         _fa_kernel, scale=1.0 / math.sqrt(d), causal=causal, window=window,
         softcap=softcap, kv_len=kv_len, block_k=block_k, q_offset=q_offset)
     item = qt.dtype.itemsize
-    # double-buffered q, k, v, o blocks, the state, the scores and probabilities
-    vmem = (2 * item * d * (2 * block_q + 2 * block_kv)
-            + 4 * block_q * (2 * LANES + d) + 12 * block_q * block_k)
+    # double-buffered q, k, v, o blocks, the state, the scores and
+    # probabilities; VMEM holds a row of a block in whole 128-lane tiles
+    width = _round_up(d, LANES)
+    vmem = (2 * item * width * (2 * block_q + 2 * block_kv)
+            + 4 * block_q * (2 * LANES + width) + 12 * block_q * block_k)
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=max(DEFAULT_VMEM, _round_up(vmem * 5 // 4, 2 ** 20)))

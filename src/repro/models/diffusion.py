@@ -7,6 +7,15 @@ comes from the timestep + pooled-condition embedding.  Layers are
 homogeneous, executed with one ``lax.scan``.  Each block runs under a
 named scope of ``repro.models.scopes`` (metadata only).
 
+``DiTConfig.block`` selects the block.  ``"single"`` is the block above.
+``"cogvideox"`` is CogVideoX's expert-AdaLN block (arXiv:2408.06072): text
+and video tokens share the joint stream and every weight, but take their
+own shift, scale and gate; LayerNorms with affine weights, per-head q/k
+LayerNorm, 3-D rotary positions on the video tokens, biased projections.
+With ``guidance_scale`` other than 1, ``ddim_denoise`` runs the network on
+the prompt's and the empty prompt's condition in one batch
+(classifier-free guidance).
+
 The Diffuse stage runs ``num_steps`` denoising iterations of this network —
 the compute-dominant, SP-scalable stage the paper's dispatcher reasons
 about.
@@ -16,7 +25,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,10 +54,20 @@ class DiTConfig:
     dtype: Any = jnp.bfloat16
     norm_eps: float = 1e-6
     use_fused_adaln: bool = False  # route modulated norms through the Pallas kernel
+    block: str = "single"          # "single" | "cogvideox"
+    guidance_scale: float = 1.0    # classifier-free guidance; 1 is none
     source: str = ""
+
+    @property
+    def cond_batch(self) -> int:
+        """Condition rows per request: the prompt, and with guidance the
+        empty prompt after it."""
+        return 1 if self.guidance_scale == 1.0 else 2
 
 
 def init(cfg: DiTConfig, key: Array) -> dict:
+    if cfg.block == "cogvideox":
+        return _init_cogvideox(cfg, key)
     d = cfg.d_model
     ks = common.split_keys(key, 8)
     scale_o = 1.0 / max(1, cfg.num_layers) ** 0.5
@@ -110,12 +129,16 @@ def joint_attention(q: Array, k: Array, v: Array) -> Array:
 
 
 def forward(cfg: DiTConfig, params: dict, latents: Array, t: Array,
-            cond: Array, cond_pooled: Optional[Array] = None) -> Array:
+            cond: Array, cond_pooled: Optional[Array] = None,
+            grid: Optional[Tuple[int, int, int]] = None) -> Array:
     """One denoising network evaluation.
 
     latents: (B, Lx, latent_dim); t: (B,); cond: (B, Lc, cond_dim).
-    Returns predicted noise (B, Lx, latent_dim).
+    Returns predicted noise (B, Lx, latent_dim).  The cogvideox block also
+    takes the latent ``grid`` (frames, h, w) its rotary positions follow.
     """
+    if cfg.block == "cogvideox":
+        return _forward_cogvideox(cfg, params, latents, t, cond, grid)
     b, lx, _ = latents.shape
     lc = cond.shape[1]
     h = cfg.num_heads
@@ -172,16 +195,181 @@ def forward(cfg: DiTConfig, params: dict, latents: Array, t: Array,
         return eps.astype(jnp.float32)
 
 
+# ---------------------------------------------------------------------------
+# CogVideoX's block (arXiv:2408.06072; the CogVideoX-5B transformer)
+# ---------------------------------------------------------------------------
+
+QK_NORM_EPS = 1e-6
+ROPE_THETA = 10000.0
+
+
+def _init_cogvideox(cfg: DiTConfig, key: Array) -> dict:
+    """Weights of the cogvideox block.  ``time_embed_dim`` is the width of
+    the time embedding e (the sinusoid has ``d_model`` channels).  Each
+    bias is ``<weight>_b``; each norm's gain is ``<norm>_w``, stored as an
+    offset from 1 as the repo's other norms are.  Biases, gain offsets and
+    modulations start at zero, so blocks start as the identity."""
+    d, e, f = cfg.d_model, cfg.time_embed_dim, cfg.d_ff
+    dh = d // cfg.num_heads
+    ks = common.split_keys(key, 6)
+    scale_o = 1.0 / max(1, cfg.num_layers) ** 0.5
+    zeros = lambda *shape: jnp.zeros(shape, cfg.dtype)
+
+    def layer_init(k):
+        kk = common.split_keys(k, 6)
+        return {
+            "mod1": zeros(e, 6 * d), "mod1_b": zeros(6 * d),
+            "mod2": zeros(e, 6 * d), "mod2_b": zeros(6 * d),
+            "ln1_w": zeros(d), "ln1_b": zeros(d),
+            "ln2_w": zeros(d), "ln2_b": zeros(d),
+            "wq": dense_init(kk[0], (d, d), cfg.dtype), "wq_b": zeros(d),
+            "wk": dense_init(kk[1], (d, d), cfg.dtype), "wk_b": zeros(d),
+            "wv": dense_init(kk[2], (d, d), cfg.dtype), "wv_b": zeros(d),
+            "q_norm_w": zeros(dh), "q_norm_b": zeros(dh),
+            "k_norm_w": zeros(dh), "k_norm_b": zeros(dh),
+            "wo": dense_init(kk[3], (d, d), cfg.dtype, scale=scale_o), "wo_b": zeros(d),
+            "w_up": dense_init(kk[4], (d, f), cfg.dtype), "w_up_b": zeros(f),
+            "w_down": dense_init(kk[5], (f, d), cfg.dtype, scale=scale_o),
+            "w_down_b": zeros(d),
+        }
+
+    lkeys = jnp.stack(common.split_keys(ks[0], cfg.num_layers))
+    return {
+        "text_in": dense_init(ks[1], (cfg.cond_dim, d), cfg.dtype), "text_in_b": zeros(d),
+        "x_in": dense_init(ks[2], (cfg.latent_dim, d), cfg.dtype), "x_in_b": zeros(d),
+        "t_mlp1": dense_init(ks[3], (d, e), cfg.dtype), "t_mlp1_b": zeros(e),
+        "t_mlp2": dense_init(ks[4], (e, e), cfg.dtype), "t_mlp2_b": zeros(e),
+        "layers": jax.vmap(layer_init)(lkeys),
+        "final_norm_w": zeros(d), "final_norm_b": zeros(d),
+        "final_mod": zeros(e, 2 * d), "final_mod_b": zeros(2 * d),
+        "out_norm_w": zeros(d), "out_norm_b": zeros(d),
+        "x_out": dense_init(ks[5], (d, cfg.latent_dim), cfg.dtype, scale=0.02),
+        "x_out_b": zeros(cfg.latent_dim),
+    }
+
+
+def rope_3d(grid: Tuple[int, int, int], head_dim: int, text_len: int):
+    """(cos, sin), each (text_len + f*h*w, head_dim) float32: CogVideoX's
+    3-D rotary positions.  A head's dims split dh/4, 3dh/8, 3dh/8 over
+    (frame, row, column) at the latent grid's integer positions, frame-major
+    as the tokens are; each part has its own frequencies theta^(-2i/part),
+    each for one interleaved pair of dims.  The text rows, first, rotate by
+    nothing (cos 1, sin 0)."""
+    parts = (head_dim // 4, head_dim // 8 * 3, head_dim // 8 * 3)
+    pos = jnp.meshgrid(*(jnp.arange(n, dtype=jnp.float32) for n in grid),
+                       indexing="ij")
+    ang = jnp.concatenate([p.reshape(-1, 1) * common.rope_freqs(n, ROPE_THETA)
+                           for p, n in zip(pos, parts)], axis=-1)
+    ang = jnp.concatenate([jnp.zeros((text_len, head_dim), jnp.float32),
+                           jnp.repeat(ang, 2, axis=-1)])
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def _layer_norm(x: Array, w: Array, b: Array, eps: float) -> Array:
+    """Affine LayerNorm in float32, the gain stored as an offset from 1."""
+    return common.layer_norm(x.astype(jnp.float32), 1.0 + w.astype(jnp.float32), b, eps)
+
+
+def _qk_norm_rope(x: Array, w: Array, b: Array, cos: Array, sin: Array) -> Array:
+    """Per-head LayerNorm of q or k (B, L, H, Dh), then each interleaved
+    pair (x0, x1) rotated to (x0 cos - x1 sin, x1 cos + x0 sin).  The pairs
+    turn to (-x1, x0) by a signed permutation product, exact at the highest
+    precision: on a TPU it moves a third of the bytes that slicing the
+    pairs apart does (v5e compile of the 480-px cogvideox layer)."""
+    x = _layer_norm(x, w, b, QK_NORM_EPS)
+    dh = x.shape[-1]
+    even, odd = jnp.arange(0, dh, 2), jnp.arange(1, dh, 2)
+    turn = jnp.zeros((dh, dh), jnp.float32).at[odd, even].set(-1.0).at[even, odd].set(1.0)
+    turned = jnp.einsum("blhd,de->blhe", x, turn, precision=jax.lax.Precision.HIGHEST)
+    return x * cos[None, :, None] + turned * sin[None, :, None]
+
+
+def _forward_cogvideox(cfg: DiTConfig, params: dict, latents: Array, t: Array,
+                       cond: Array, grid: Tuple[int, int, int]) -> Array:
+    """One evaluation of the cogvideox network; the text first in the joint
+    stream.  Per block, from the time embedding e: (shift, scale, gate) for
+    the video tokens and the same for the text tokens, twice (before the
+    attention and before the MLP); both kinds share one affine LayerNorm
+    and every projection."""
+    b, lx, _ = latents.shape
+    lc = cond.shape[1]
+    l = lx + lc
+    d, nh = cfg.d_model, cfg.num_heads
+    dh = d // nh
+    dt = cfg.dtype
+
+    def dense(x, w, bias):
+        return jnp.einsum("...i,io->...o", x.astype(dt), w) + bias
+
+    def silu(x):
+        return jax.nn.silu(x.astype(jnp.float32)).astype(dt)
+
+    with jax.named_scope(scopes.DIT_EMBED):
+        x = jnp.concatenate([dense(cond, params["text_in"], params["text_in_b"]),
+                             dense(latents, params["x_in"], params["x_in_b"])], axis=1)
+        e = dense(timestep_embedding(t, d), params["t_mlp1"], params["t_mlp1_b"])
+        e = dense(silu(e), params["t_mlp2"], params["t_mlp2_b"])
+        se = silu(e)                 # what every modulation projects
+        cos, sin = rope_3d(grid, dh, lc)
+        is_text = (jnp.arange(l) < lc)[None, :, None]
+
+    def modulated(x, w, bias, mod, mod_b):
+        """LN(x)(1 + scale) + shift, and the gate, per token's kind."""
+        m = dense(se, mod, mod_b).astype(jnp.float32).reshape(b, 2, 3, 1, d)
+        shift, scale, gate = (jnp.where(is_text, m[:, 1, i], m[:, 0, i]) for i in range(3))
+        h = _layer_norm(x, w, bias, cfg.norm_eps) * (1.0 + scale) + shift
+        return h.astype(dt), gate.astype(dt)
+
+    def block(x, p):
+        with jax.named_scope(scopes.DIT_ADALN):
+            h, gate = modulated(x, p["ln1_w"], p["ln1_b"], p["mod1"], p["mod1_b"])
+        with jax.named_scope(scopes.DIT_QKV):
+            q, k, v = (dense(h, p[f"w{n}"], p[f"w{n}_b"]).reshape(b, l, nh, dh)
+                       for n in "qkv")
+        with jax.named_scope(scopes.DIT_QK_ROPE):
+            q = _qk_norm_rope(q, p["q_norm_w"], p["q_norm_b"], cos, sin).astype(dt)
+            k = _qk_norm_rope(k, p["k_norm_w"], p["k_norm_b"], cos, sin).astype(dt)
+        with jax.named_scope(scopes.DIT_ATTENTION):
+            a = joint_attention(q, k, v)
+        with jax.named_scope(scopes.DIT_ATTN_OUT):
+            x = x + gate * dense(a.reshape(b, l, d), p["wo"], p["wo_b"])
+        with jax.named_scope(scopes.DIT_ADALN):
+            h, gate = modulated(x, p["ln2_w"], p["ln2_b"], p["mod2"], p["mod2_b"])
+        with jax.named_scope(scopes.DIT_MLP):
+            f = jax.nn.gelu(dense(h, p["w_up"], p["w_up_b"]).astype(jnp.float32),
+                            approximate=True)
+            x = x + gate * dense(f, p["w_down"], p["w_down_b"])
+        return x, 0
+
+    with jax.named_scope(scopes.DIT_LAYERS):
+        x, _ = jax.lax.scan(block, x, params["layers"])
+    with jax.named_scope(scopes.DIT_FINAL):
+        # the final LayerNorm acts per token: over the joint stream, its
+        # video rows are these
+        h = _layer_norm(x[:, lc:], params["final_norm_w"], params["final_norm_b"],
+                        cfg.norm_eps)
+        shift, scale = jnp.split(dense(se, params["final_mod"], params["final_mod_b"])
+                                 .astype(jnp.float32)[:, None], 2, axis=-1)
+        h = _layer_norm(h, params["out_norm_w"], params["out_norm_b"],
+                        cfg.norm_eps) * (1.0 + scale) + shift
+        return dense(h, params["x_out"], params["x_out_b"]).astype(jnp.float32)
+
+
 def ddim_denoise(cfg: DiTConfig, params: dict, noise: Array, cond: Array,
-                 num_steps: int, key: Optional[Array] = None) -> Array:
+                 num_steps: int, key: Optional[Array] = None,
+                 grid: Optional[Tuple[int, int, int]] = None) -> Array:
     """Multi-step denoising loop (the Diffuse stage's runtime body).
 
-    DDIM with a linear alpha-bar schedule; deterministic (eta=0).
+    DDIM with a linear alpha-bar schedule; deterministic (eta=0).  With
+    guidance, ``cond`` is (2B, Lc, cond_dim), the prompts' rows and then
+    the empty prompts'; each step runs the network once on ``[x; x]`` and
+    takes ``e_u + s (e_c - e_u)``.
     """
     with jax.named_scope(scopes.DDIM):
         betas = jnp.linspace(1e-4, 0.02, 1000, dtype=jnp.float32)
         alpha_bar = jnp.cumprod(1.0 - betas)
         ts = jnp.linspace(999, 0, num_steps).astype(jnp.int32)
+    rope_grid = {} if grid is None else {"grid": grid}   # for the block that reads it
 
     def step(i, x):
         with jax.named_scope(scopes.DDIM):
@@ -191,7 +379,14 @@ def ddim_denoise(cfg: DiTConfig, params: dict, noise: Array, cond: Array,
             ab_t = alpha_bar[t]
             ab_n = jnp.where(t_next >= 0, alpha_bar[jnp.maximum(t_next, 0)], 1.0)
             tb = jnp.full((x.shape[0],), t, jnp.float32)
-        eps = forward(cfg, params, x, tb, cond)
+        if cfg.guidance_scale == 1.0:
+            eps = forward(cfg, params, x, tb, cond, **rope_grid)
+        else:
+            both = forward(cfg, params, jnp.concatenate([x, x]),
+                           jnp.concatenate([tb, tb]), cond, **rope_grid)
+            with jax.named_scope(scopes.DDIM):
+                e_c, e_u = jnp.split(both, 2)
+                eps = e_u + cfg.guidance_scale * (e_c - e_u)
         with jax.named_scope(scopes.DDIM):
             x0 = (x - jnp.sqrt(1 - ab_t) * eps) / jnp.sqrt(ab_t)
             return jnp.sqrt(ab_n) * x0 + jnp.sqrt(1 - ab_n) * eps

@@ -30,14 +30,23 @@ class PipelineConfig:
     num_steps: int                    # denoising steps (Table 5)
     max_cond_len: int = 128
     is_video: bool = False
+    clip_frames: int = 0              # a video of fixed length, in frames; 0: from seconds
+    aspect: float = 1.0               # width over height
     source: str = ""
 
     def latent_grid(self, resolution: int, seconds: float = 0.0) -> Tuple[int, int, int]:
-        """(frames, h, w) latent geometry. 8x VAE + patch 2 -> /16 per side;
-        video: 4x temporal compression at 16 fps."""
-        side = max(2, resolution // 16)
-        frames = max(1, int(seconds * 16) // 4) if self.is_video else 1
-        return frames, side, side
+        """(frames, h, w) latent geometry of a ``resolution``-px-high output.
+        8x VAE + patch 2 -> /16 per side.  Video: a clip of ``clip_frames``
+        keeps its first frame and compresses the rest 4x in time (a causal
+        VAE: 49 frames -> 13); without it, 4x compression of ``seconds`` at
+        16 fps."""
+        h = max(2, resolution // 16)
+        w = max(2, round(resolution * self.aspect) // 16)
+        if self.clip_frames:
+            frames = (self.clip_frames - 1) // 4 + 1
+        else:
+            frames = max(1, int(seconds * 16) // 4) if self.is_video else 1
+        return frames, h, w
 
     def latent_tokens(self, resolution: int, seconds: float = 0.0) -> int:
         f, h, w = self.latent_grid(resolution, seconds)
@@ -56,8 +65,13 @@ def init(cfg: PipelineConfig, key: Array) -> Dict[str, Any]:
 # --- Stage apply functions (each independently dispatchable) ---------------
 
 def encode(cfg: PipelineConfig, params: Dict, tokens: Array) -> Array:
-    """Stage E: prompt tokens (B, Lc) -> condition embeddings (B, Lc, D_enc)."""
+    """Stage E: prompt tokens (B, Lc) -> condition embeddings (B, Lc, D_enc).
+
+    With guidance the empty prompt (all pad, id 0) is encoded after the
+    prompts: (2B, Lc, D_enc), the condition ``ddim_denoise`` takes."""
     ecfg = cfg.encoder
+    if cfg.dit.cond_batch == 2:
+        tokens = jnp.concatenate([tokens, jnp.zeros_like(tokens)])
     x = transformer.embed_tokens(ecfg, params["encode"], tokens)
     b, l, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(l, dtype=jnp.int32)[None], (b, l))
@@ -67,12 +81,15 @@ def encode(cfg: PipelineConfig, params: Dict, tokens: Array) -> Array:
 
 
 def diffuse(cfg: PipelineConfig, params: Dict, cond: Array, latent_shape,
-            key: Array, num_steps: Optional[int] = None) -> Array:
-    """Stage D: T-step denoising from Gaussian noise in latent space."""
+            key: Array, num_steps: Optional[int] = None,
+            grid: Optional[Tuple[int, int, int]] = None) -> Array:
+    """Stage D: T-step denoising from Gaussian noise in latent space.  The
+    cogvideox block places its rotary positions on the latent ``grid``."""
     steps = num_steps or cfg.num_steps
     with jax.named_scope(scopes.DDIM):
         noise = jax.random.normal(key, latent_shape, jnp.float32)
-    return diffusion.ddim_denoise(cfg.dit, params["diffuse"], noise, cond, steps)
+    return diffusion.ddim_denoise(cfg.dit, params["diffuse"], noise, cond, steps,
+                                  grid=grid)
 
 
 def decode(cfg: PipelineConfig, params: Dict, latents: Array,
@@ -99,7 +116,8 @@ def generate(cfg: PipelineConfig, params: Dict, tokens: Array, resolution: int,
     cond = encode(cfg, params, tokens)
     b = tokens.shape[0]
     lat_dim = cfg.dit.latent_dim
-    latents = diffuse(cfg, params, cond, (b, ltokens, lat_dim), key, num_steps)
+    latents = diffuse(cfg, params, cond, (b, ltokens, lat_dim), key, num_steps,
+                      grid=grid)
     return decode(cfg, params, latents, grid)
 
 

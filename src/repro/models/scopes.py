@@ -10,8 +10,9 @@ them.
 * D (``diffusion.forward`` and ``ddim_denoise``): the input projections,
   positions and time embedding; the layer loop's own work (each layer's
   weights sliced out of the stacked arrays, the counter); per layer the
-  modulation and both modulated norms, the q/k/v projections, the attention
-  core alone (scores, softmax, weighted sum: whatever implements it stays
+  modulation and both modulated norms, the q/k/v projections, the per-head
+  q/k norms and rotary positions (the cogvideox block), the attention core
+  alone (scores, softmax, weighted sum: whatever implements it stays
   inside ``dit/attention``), the output projection with its gated residual,
   the MLP with its gated residual; the final modulation and output
   projection; the sampler's starting noise and update.
@@ -26,13 +27,14 @@ DIT_EMBED = "dit/embed"
 DIT_LAYERS = "dit/layers"
 DIT_ADALN = "dit/adaln"
 DIT_QKV = "dit/qkv"
+DIT_QK_ROPE = "dit/qk_rope"
 DIT_ATTENTION = "dit/attention"
 DIT_ATTN_OUT = "dit/attn_out"
 DIT_MLP = "dit/mlp"
 DIT_FINAL = "dit/final"
 DDIM = "ddim"
-DIFFUSE = (DIT_EMBED, DIT_LAYERS, DIT_ADALN, DIT_QKV, DIT_ATTENTION,
-           DIT_ATTN_OUT, DIT_MLP, DIT_FINAL, DDIM)
+DIFFUSE = (DIT_EMBED, DIT_LAYERS, DIT_ADALN, DIT_QKV, DIT_QK_ROPE,
+           DIT_ATTENTION, DIT_ATTN_OUT, DIT_MLP, DIT_FINAL, DDIM)
 
 ENCODER_LAYERS = "encoder/layers"
 ENCODER_ATTENTION = "encoder/attention"

@@ -115,3 +115,37 @@ def test_long_diffuse_stage_runs_flash_kernel_on_v5e(one_chip, arch, res, layers
 def test_short_diffuse_stage_keeps_xla_attention_on_v5e(one_chip, res):
     """Below the kernel's joint length the sd3 D stage holds no kernel."""
     assert "tpu_custom_call" not in _diffuse_compiled(one_chip, "sd3", res).as_text()
+
+
+def test_flash_attention_at_video_length_compiles_for_v5e(one_chip):
+    """CogVideoX's joint sequence: 17,776 positions, the guided batch of 2,
+    48 heads of 64.  VMEM holds a head's K and V in 128-lane tiles, twice
+    the bytes of their 64 columns, and the kernel asks for that much."""
+    x = _sds((2, 17776, 48, 64), jnp.bfloat16, one_chip)
+    fn = jax.jit(functools.partial(ops.flash_attention, causal=False,
+                                   use_kernel=True))
+    assert "tpu_custom_call" in fn.lower(x, x, x).compile().as_text()
+
+
+def test_cogvideox_diffuse_stage_fits_v5e(one_chip):
+    """The cell's D program (6 layers, 10 guided steps at 480 px): the
+    attention is the Pallas call under ``dit/attention``, the q/k norms and
+    rotation have their own scope, and the stage fits one chip beside the
+    pipeline's 10.4 GiB of weights."""
+    from benchmarks.chip import harness
+    fam = harness.family(harness.load_cell("cogvideox.t2v").config)
+    pcfg = fam.program_config()
+    shapes = fam.param_shapes(pcfg)
+    name, fn = fam.stage_fns(pcfg, 480)["D"]
+    fn.__name__ = name
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), shapes["diffuse"])
+    cond = _sds((2, fam.cond_len, pcfg.dit.cond_dim), jnp.bfloat16, one_chip)
+    compiled = jax.jit(fn).lower(params, cond, _sds((2,), jnp.uint32, one_chip)).compile()
+    text = compiled.as_text()
+    where = hlo.op_scopes(text, scopes.DIFFUSE)
+    calls = [op.name for ops in hlo.parse_computations(text).values() for op in ops
+             if "tpu_custom_call" in op.line]
+    assert calls and {where.get(c) for c in calls} == {scopes.DIT_ATTENTION}
+    assert scopes.DIT_QK_ROPE in set(where.values())
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(shapes))
+    assert weights + compiled.memory_analysis().temp_size_in_bytes < V5E_HBM_BYTES * 0.9
