@@ -3,9 +3,10 @@
 Architecture follows Peebles & Xie DiT / SD3-style joint conditioning
 simplified to a single stream: latent patches and text-condition tokens are
 concatenated into one sequence; per-block modulation (shift/scale/gate x2)
-comes from the timestep + pooled-condition embedding.  Layers are
-homogeneous, executed with one ``lax.scan``.  Each block runs under a
-named scope of ``repro.models.scopes`` (metadata only).
+comes from the timestep + pooled-condition embedding, and depends on
+nothing else: ``ddim_denoise`` computes every step's before its step loop
+(``adaln``).  Layers are homogeneous, executed with one ``lax.scan``.  Each
+block runs under a named scope of ``repro.models.scopes`` (metadata only).
 
 ``DiTConfig.block`` selects the block.  ``"single"`` is the block above.
 ``"cogvideox"`` is CogVideoX's expert-AdaLN block (arXiv:2408.06072): text
@@ -128,6 +129,66 @@ def joint_attention(q: Array, k: Array, v: Array) -> Array:
         tpu=functools.partial(kops.flash_attention, causal=False, use_kernel=True))
 
 
+def _dense(x: Array, w: Array, bias: Optional[Array], dtype) -> Array:
+    """x @ w in ``dtype`` (+ bias)."""
+    y = jnp.einsum("...i,io->...o", x.astype(dtype), w)
+    return y if bias is None else y + bias
+
+
+def _silu(x: Array, dtype) -> Array:
+    return jax.nn.silu(x.astype(jnp.float32)).astype(dtype)
+
+
+def _time_conditioning(cfg: DiTConfig, params: dict, t: Array,
+                       cond_pooled: Optional[Array] = None) -> Array:
+    """What every modulation of the network projects, (n, k), at timesteps
+    ``t`` (n,): the time MLP's output (plus the pooled condition) for the
+    single block, silu of the time embedding e for the cogvideox block."""
+    dt = cfg.dtype
+    if cfg.block == "cogvideox":
+        e = _dense(timestep_embedding(t, cfg.d_model), params["t_mlp1"], params["t_mlp1_b"], dt)
+        return _silu(_dense(_silu(e, dt), params["t_mlp2"], params["t_mlp2_b"], dt), dt)
+    tc = _dense(timestep_embedding(t, cfg.time_embed_dim), params["t_mlp1"], None, dt)
+    if cond_pooled is not None:
+        tc = tc + cond_pooled.astype(dt)
+    return _dense(_silu(tc, dt), params["t_mlp2"], None, dt)
+
+
+# each layer's modulation weights, by block kind; the final one is
+# ``final_mod`` in both
+_MOD_WEIGHTS = {"single": ("mod",), "cogvideox": ("mod1", "mod2")}
+
+
+def adaln(cfg: DiTConfig, params: dict, t: Array,
+          cond_pooled: Optional[Array] = None) -> Tuple[dict, dict]:
+    """Every AdaLN modulation the network applies at timesteps ``t`` (n,),
+    keyed by its weight's name (with its ``_b`` bias, where the block has
+    one): each layer's, (L, n, k), one product per weight over the stacked
+    layers; and the final one, (n, k).  They read ``t`` and the pooled
+    condition, never the latents, so ``ddim_denoise`` takes every step's
+    before its loop and no step reads a modulation weight."""
+    with jax.named_scope(scopes.DIT_EMBED):
+        c = _time_conditioning(cfg, params, t, cond_pooled)
+
+    def project(w, bias):
+        m = jnp.einsum("ni,...io->...no", c, w)
+        return m if bias is None else m + jnp.expand_dims(bias, -2)
+
+    with jax.named_scope(scopes.DIT_ADALN):
+        layers = params["layers"]
+        per_layer = {w: project(layers[w], layers.get(w + "_b"))
+                     for w in _MOD_WEIGHTS[cfg.block]}
+        final = {"final_mod": project(params["final_mod"], params.get("final_mod_b"))}
+    return per_layer, final
+
+
+def _with_adaln(params: dict, per_layer: dict, final: dict) -> dict:
+    """``params`` carrying modulations for ``forward`` to apply: each
+    layer's under ``params["layers"]["adaln"]``, the layer axis leading as
+    on every other per-layer leaf; the final one under ``params["adaln"]``."""
+    return dict(params, adaln=final, layers=dict(params["layers"], adaln=per_layer))
+
+
 def forward(cfg: DiTConfig, params: dict, latents: Array, t: Array,
             cond: Array, cond_pooled: Optional[Array] = None,
             grid: Optional[Tuple[int, int, int]] = None) -> Array:
@@ -136,9 +197,14 @@ def forward(cfg: DiTConfig, params: dict, latents: Array, t: Array,
     latents: (B, Lx, latent_dim); t: (B,); cond: (B, Lc, cond_dim).
     Returns predicted noise (B, Lx, latent_dim).  The cogvideox block also
     takes the latent ``grid`` (frames, h, w) its rotary positions follow.
+    The modulations come from ``t`` and ``cond_pooled`` (``adaln``), or,
+    where ``params`` carries them (``_with_adaln``), as given: rows of one
+    timestep, (1, k), or of each row's.
     """
+    if "adaln" not in params:
+        params = _with_adaln(params, *adaln(cfg, params, t, cond_pooled))
     if cfg.block == "cogvideox":
-        return _forward_cogvideox(cfg, params, latents, t, cond, grid)
+        return _forward_cogvideox(cfg, params, latents, cond, grid)
     b, lx, _ = latents.shape
     lc = cond.shape[1]
     h = cfg.num_heads
@@ -157,17 +223,9 @@ def forward(cfg: DiTConfig, params: dict, latents: Array, t: Array,
                               jnp.cos(pos[:, None] * pf[1][None])], axis=-1)
         x = x + pe[None].astype(cfg.dtype)
 
-        temb = timestep_embedding(t, cfg.time_embed_dim)
-        tc = jnp.einsum("be,ed->bd", temb.astype(cfg.dtype), params["t_mlp1"])
-        if cond_pooled is not None:
-            tc = tc + cond_pooled.astype(cfg.dtype)
-        tc = jnp.einsum("bd,de->be",
-                        jax.nn.silu(tc.astype(jnp.float32)).astype(cfg.dtype),
-                        params["t_mlp2"])
-
     def block(x, p):
         with jax.named_scope(scopes.DIT_ADALN):
-            mod = jnp.einsum("bd,de->be", tc, p["mod"]).reshape(b, 6, cfg.d_model)
+            mod = p["adaln"]["mod"].reshape(-1, 6, cfg.d_model)
             s1, sh1, g1, s2, sh2, g2 = [mod[:, i] for i in range(6)]
             hn = _modulated_norm(cfg, x, s1, sh1)
         with jax.named_scope(scopes.DIT_QKV):
@@ -189,7 +247,7 @@ def forward(cfg: DiTConfig, params: dict, latents: Array, t: Array,
     with jax.named_scope(scopes.DIT_LAYERS):
         x, _ = jax.lax.scan(block, x, params["layers"])
     with jax.named_scope(scopes.DIT_FINAL):
-        fmod = jnp.einsum("bd,de->be", tc, params["final_mod"]).reshape(b, 2, cfg.d_model)
+        fmod = params["adaln"]["final_mod"].reshape(-1, 2, cfg.d_model)
         x = _modulated_norm(cfg, x, fmod[:, 0], fmod[:, 1])
         eps = jnp.einsum("bld,dc->blc", x[:, lc:, :], params["x_out"])
         return eps.astype(jnp.float32)
@@ -284,13 +342,13 @@ def _qk_norm_rope(x: Array, w: Array, b: Array, cos: Array, sin: Array) -> Array
     return x * cos[None, :, None] + turned * sin[None, :, None]
 
 
-def _forward_cogvideox(cfg: DiTConfig, params: dict, latents: Array, t: Array,
+def _forward_cogvideox(cfg: DiTConfig, params: dict, latents: Array,
                        cond: Array, grid: Tuple[int, int, int]) -> Array:
     """One evaluation of the cogvideox network; the text first in the joint
-    stream.  Per block, from the time embedding e: (shift, scale, gate) for
-    the video tokens and the same for the text tokens, twice (before the
-    attention and before the MLP); both kinds share one affine LayerNorm
-    and every projection."""
+    stream.  Per block, the modulations ``params`` carries (``adaln``, from
+    the time embedding e): (shift, scale, gate) for the video tokens and the
+    same for the text tokens, twice (before the attention and before the
+    MLP); both kinds share one affine LayerNorm and every projection."""
     b, lx, _ = latents.shape
     lc = cond.shape[1]
     l = lx + lc
@@ -298,31 +356,24 @@ def _forward_cogvideox(cfg: DiTConfig, params: dict, latents: Array, t: Array,
     dh = d // nh
     dt = cfg.dtype
 
-    def dense(x, w, bias):
-        return jnp.einsum("...i,io->...o", x.astype(dt), w) + bias
-
-    def silu(x):
-        return jax.nn.silu(x.astype(jnp.float32)).astype(dt)
+    dense = functools.partial(_dense, dtype=dt)
 
     with jax.named_scope(scopes.DIT_EMBED):
         x = jnp.concatenate([dense(cond, params["text_in"], params["text_in_b"]),
                              dense(latents, params["x_in"], params["x_in_b"])], axis=1)
-        e = dense(timestep_embedding(t, d), params["t_mlp1"], params["t_mlp1_b"])
-        e = dense(silu(e), params["t_mlp2"], params["t_mlp2_b"])
-        se = silu(e)                 # what every modulation projects
         cos, sin = rope_3d(grid, dh, lc)
         is_text = (jnp.arange(l) < lc)[None, :, None]
 
-    def modulated(x, w, bias, mod, mod_b):
+    def modulated(x, w, bias, mod):
         """LN(x)(1 + scale) + shift, and the gate, per token's kind."""
-        m = dense(se, mod, mod_b).astype(jnp.float32).reshape(b, 2, 3, 1, d)
+        m = mod.astype(jnp.float32).reshape(-1, 2, 3, 1, d)
         shift, scale, gate = (jnp.where(is_text, m[:, 1, i], m[:, 0, i]) for i in range(3))
         h = _layer_norm(x, w, bias, cfg.norm_eps) * (1.0 + scale) + shift
         return h.astype(dt), gate.astype(dt)
 
     def block(x, p):
         with jax.named_scope(scopes.DIT_ADALN):
-            h, gate = modulated(x, p["ln1_w"], p["ln1_b"], p["mod1"], p["mod1_b"])
+            h, gate = modulated(x, p["ln1_w"], p["ln1_b"], p["adaln"]["mod1"])
         with jax.named_scope(scopes.DIT_QKV):
             q, k, v = (dense(h, p[f"w{n}"], p[f"w{n}_b"]).reshape(b, l, nh, dh)
                        for n in "qkv")
@@ -334,7 +385,7 @@ def _forward_cogvideox(cfg: DiTConfig, params: dict, latents: Array, t: Array,
         with jax.named_scope(scopes.DIT_ATTN_OUT):
             x = x + gate * dense(a.reshape(b, l, d), p["wo"], p["wo_b"])
         with jax.named_scope(scopes.DIT_ADALN):
-            h, gate = modulated(x, p["ln2_w"], p["ln2_b"], p["mod2"], p["mod2_b"])
+            h, gate = modulated(x, p["ln2_w"], p["ln2_b"], p["adaln"]["mod2"])
         with jax.named_scope(scopes.DIT_MLP):
             f = jax.nn.gelu(dense(h, p["w_up"], p["w_up_b"]).astype(jnp.float32),
                             approximate=True)
@@ -348,8 +399,8 @@ def _forward_cogvideox(cfg: DiTConfig, params: dict, latents: Array, t: Array,
         # video rows are these
         h = _layer_norm(x[:, lc:], params["final_norm_w"], params["final_norm_b"],
                         cfg.norm_eps)
-        shift, scale = jnp.split(dense(se, params["final_mod"], params["final_mod_b"])
-                                 .astype(jnp.float32)[:, None], 2, axis=-1)
+        shift, scale = jnp.split(params["adaln"]["final_mod"].astype(jnp.float32)[:, None],
+                                 2, axis=-1)
         h = _layer_norm(h, params["out_norm_w"], params["out_norm_b"],
                         cfg.norm_eps) * (1.0 + scale) + shift
         return dense(h, params["x_out"], params["x_out_b"]).astype(jnp.float32)
@@ -370,6 +421,8 @@ def ddim_denoise(cfg: DiTConfig, params: dict, noise: Array, cond: Array,
         alpha_bar = jnp.cumprod(1.0 - betas)
         ts = jnp.linspace(999, 0, num_steps).astype(jnp.int32)
     rope_grid = {} if grid is None else {"grid": grid}   # for the block that reads it
+    # every step's modulations, each weight read once: (L, steps, k), (steps, k)
+    per_layer, final = adaln(cfg, params, ts.astype(jnp.float32))
 
     def step(i, x):
         with jax.named_scope(scopes.DDIM):
@@ -379,10 +432,13 @@ def ddim_denoise(cfg: DiTConfig, params: dict, noise: Array, cond: Array,
             ab_t = alpha_bar[t]
             ab_n = jnp.where(t_next >= 0, alpha_bar[jnp.maximum(t_next, 0)], 1.0)
             tb = jnp.full((x.shape[0],), t, jnp.float32)
+            rows = lambda m, axis: jax.lax.dynamic_index_in_dim(m, i, axis)  # (..., 1, k)
+            p = _with_adaln(params, {w: rows(m, 1) for w, m in per_layer.items()},
+                            {w: rows(m, 0) for w, m in final.items()})
         if cfg.guidance_scale == 1.0:
-            eps = forward(cfg, params, x, tb, cond, **rope_grid)
+            eps = forward(cfg, p, x, tb, cond, **rope_grid)
         else:
-            both = forward(cfg, params, jnp.concatenate([x, x]),
+            both = forward(cfg, p, jnp.concatenate([x, x]),
                            jnp.concatenate([tb, tb]), cond, **rope_grid)
             with jax.named_scope(scopes.DDIM):
                 e_c, e_u = jnp.split(both, 2)

@@ -180,10 +180,11 @@ def _d_names(arch: str, res: int = 64) -> dict:
 
 
 # (instruction count, sha256 of the names as sorted JSON) of each smoke D
-# program, taken before the cogvideox block and guidance were added
+# program, taken before the cogvideox block and guidance were added, and
+# re-taken when the AdaLN modulation moved out of the step loop
 IMAGE_D_NAMES = {
-    "sd3": (954, "ce30402cb28ccfb511af0f3a66de503e5a375df9d3eb6cc8f61892f9d9646e19"),
-    "flux": (934, "143fe0f4902e0cc934acf360a86fb98253398e6ed99917f865b0654b1e46017f"),
+    "sd3": (979, "77316c142a5818018cca7a0b041923508f1a782a42df44ea16cb41df2d32b6c3"),
+    "flux": (939, "aebeb85f6ed79a395cc686bb48fbba7052fc14e7dda6e6637def99fab59cd001"),
 }
 
 
